@@ -29,7 +29,7 @@ def main(csv_rows):
 
     # Q2-scale history: 120-day mean = 10.4M records/thing at 1Hz; we build
     # a scaled history and compare edge vs VDC(JIT-offload kernel) paths.
-    hx = HybridExecutor(edge_budget=100_000)
+    hx = HybridExecutor(edge_budget=100_000, interpret=True)   # host CPU
     for n in (10_000, 1_000_000, 10_368_000):
         vals = np.random.default_rng(0).standard_normal(n).astype(np.float32)
         t0 = time.perf_counter()

@@ -138,7 +138,9 @@ SCENARIOS = (scenario_light_windows, scenario_heavy_analytics,
 
 # ---------------------------------------------------------------------------
 def run_scenario(sc: Scenario, calibrate: bool = False) -> Dict:
-    cal: Optional[KernelCalibrator] = KernelCalibrator() if calibrate else None
+    # host-CPU benchmark: the calibrator's dry-runs use the interpreter
+    cal: Optional[KernelCalibrator] = (KernelCalibrator(interpret=True)
+                                       if calibrate else None)
     engine = sc.spec.compile(calibrator=cal)
     names = list(engine.topology)
     t0 = time.perf_counter()

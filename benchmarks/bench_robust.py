@@ -95,8 +95,9 @@ def agreement_block() -> List[Dict]:
 # ---------------------------------------------------------------------------
 # Block 2: ensemble throughput vs sequential DES
 # ---------------------------------------------------------------------------
-def throughput_block(n_realizations: int = 256, n_plans: int = 32,
-                     des_samples: int = 2) -> Dict:
+def heavy_analytics_plans(n_plans: int = 32):
+    """The throughput block's workload: the ``heavy_analytics`` spec,
+    its compiled engine and its first ``n_plans`` enumerated plans."""
     sc = next(b() for b in PLACEMENT_SCENARIOS
               if b().name == "heavy_analytics")
     eng = sc.spec.compile()
@@ -104,9 +105,15 @@ def throughput_block(n_realizations: int = 256, n_plans: int = 32,
     sites = tuple(eng.info().fleet.site_names)
     plans = list(enumerate_plans(names, (4, 8, 16), (1.0,),
                                  edge_sites=sites))[:n_plans]
+    return sc.spec, eng, plans
+
+
+def throughput_block(n_realizations: int = 256, n_plans: int = 32,
+                     des_samples: int = 2) -> Dict:
+    spec, eng, plans = heavy_analytics_plans(n_plans)
 
     t0 = time.perf_counter()
-    ens = ScenarioEnsemble.from_spec(sc.spec, n=n_realizations, engine=eng)
+    ens = ScenarioEnsemble.from_spec(spec, n=n_realizations, engine=eng)
     setup_s = time.perf_counter() - t0
 
     t0 = time.perf_counter()

@@ -57,6 +57,9 @@ def main() -> None:
         want = {"placement", "online", "search", "robust", "serve",
                 "fleet", "chaos"} if args.smoke else {"placement"}
 
+    from repro.utils.compile_cache import enable_compile_cache
+    enable_compile_cache()
+
     csv_rows: list = []
     failures = []
 

@@ -53,8 +53,10 @@ print(f"store: {store.resident_chunks} edge-resident chunks, "
       f"{store.spill_events} spilled to VDC storage")
 
 # Q2-scale: a 120-day history doesn't fit the edge -> JIT offload to the
-# VDC (scaled down in --smoke so CI stays fast)
-hx = HybridExecutor(edge_budget=100_000)
+# VDC (scaled down in --smoke so CI stays fast). This example runs on the
+# CPU, so the offload kernel runs in the Pallas interpreter; chip_smoke.py
+# runs the same offload compiled on the TPU.
+hx = HybridExecutor(edge_budget=100_000, interpret=True)
 n_hist = 1_000_000 if SMOKE else 10_368_000   # 120d @ 1Hz when full
 history = np.abs(np.random.default_rng(0).standard_normal(
     n_hist)).astype(np.float32) * 20e6

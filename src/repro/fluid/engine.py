@@ -450,6 +450,10 @@ class FluidEngine:
         u0 = f32(np.eye(1, U, 0)[0])     # [U] one-hot on the farm slot
         rps, grid, dl_user = self.records_per_step, self.grid_chips, \
             self.dl_user_s
+        # f32 contractions at full precision: with the TPU's default an
+        # f32 matmul is one bf16 pass, which the CPU never does
+        hi = lax.Precision.HIGHEST
+        mm = lambda a, b: jnp.matmul(a, b, precision=hi)
 
         def curve(x, soft, hard):
             # ValueCurve with (v_max, v_min) = (1, 0.1): full value at or
@@ -479,7 +483,7 @@ class FluidEngine:
                 dur_d = steps * plan["tstep"]
                 isdc = plan["isdc"]
                 edge_work = (1.0 - isdc) * dur_e * fires_t
-                work_j = plan["onehot"].T @ edge_work             # [J]
+                work_j = mm(plan["onehot"].T, edge_work)          # [J]
                 # origin record counts per fire: the farm slot scales
                 # with the realization's slide-window modulation,
                 # upstream slots fire once per upstream fire regardless
@@ -495,13 +499,15 @@ class FluidEngine:
                     oreg = plan["oreg"]                       # [S, U, R]
                     upsec_su = plan["act"] * c * plan["upsec_pr"]
                     up_work_r = jnp.einsum(
-                        "su,sur->r", upsec_su * fires_t[:, None], oreg)
-                    q_up_su = (oreg @ q_factor_jnp(jnp.minimum(
+                        "su,sur->r", upsec_su * fires_t[:, None], oreg,
+                        precision=hi)
+                    q_up_su = mm(oreg, q_factor_jnp(jnp.minimum(
                         up_work_r / dt, _UPLINK_Q_CLAMP)))    # [S, U]
                     rapsec_su = plan["act"] * c * plan["rap_upsec_pr"]
                     rap_work_r = jnp.einsum(
-                        "su,sur->r", rapsec_su * fires_t[:, None], oreg)
-                    q_rap_su = (oreg @ q_factor_jnp(jnp.minimum(
+                        "su,sur->r", rapsec_su * fires_t[:, None], oreg,
+                        precision=hi)
+                    q_rap_su = mm(oreg, q_factor_jnp(jnp.minimum(
                         rap_work_r / dt, _UPLINK_Q_CLAMP)))
                     haul = ((plan["act"]
                              * (plan["rtt_leg"]
@@ -510,8 +516,8 @@ class FluidEngine:
                                 + plan["rap_rtt"]
                                 + c * plan["rap_upsec_pr"] * q_rap_su
                                 + c * plan["rap_dn_pr"])).sum(-1)
-                            + (plan["act"] * (oreg @ Bup)).max(-1)
-                            + (plan["rap_uses"] * (oreg @ Brap)).max(-1))
+                            + (plan["act"] * mm(oreg, Bup)).max(-1)
+                            + (plan["rap_uses"] * mm(oreg, Brap)).max(-1))
                 else:
                     upsec = (plan["act"] * c * plan["upsec_pr"]).sum(-1)
                     up_work = (upsec * fires_t).sum()
@@ -524,9 +530,9 @@ class FluidEngine:
                             + plan["uses_up"] * Bup)
                 demand = (isdc * plan["chips"] * dur_d * fires_t).sum() / dt
                 dc_over = jnp.maximum(1.0, demand / grid)
-                rw = plan["alignsite"] @ edge_work
-                B_here = plan["onehot"] @ B
-                recov_s = plan["onehot"] @ recov_t
+                rw = mm(plan["alignsite"], edge_work)
+                B_here = mm(plan["onehot"], B)
+                recov_s = mm(plan["onehot"], recov_t)
                 stall_x = jnp.maximum(0.0, plan["stall"] - tb)
                 lat_e = (B_here + rw + dur_e + plan["hop"] + haul
                          + recov_s + stall_x)

@@ -14,7 +14,7 @@ from repro.kernels.flash_attention.kernel import flash_attention_bhsd
                                              "interpret"))
 def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
                     causal: bool = True, block_q: int = 128,
-                    block_k: int = 128, interpret: bool = True) -> jax.Array:
+                    block_k: int = 128, interpret: bool = False) -> jax.Array:
     """q: [B, Sq, H, d]; k/v: [B, Skv, KV, d] (GQA) → [B, Sq, H, d].
 
     Pads sequence dims up to block multiples (padded kv masked inside the

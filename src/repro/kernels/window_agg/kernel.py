@@ -21,10 +21,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# jax < 0.5 names the TPU compile options TPUCompilerParams
-_CompilerParams = getattr(pltpu, "CompilerParams",
-                          getattr(pltpu, "TPUCompilerParams", None))
-
 INIT = {"max": -3.4e38, "min": 3.4e38, "sum": 0.0}
 
 
@@ -44,7 +40,7 @@ def _segment_kernel(x_ref, o_ref, *, agg: str, stride: int, block_o: int):
 
 def segment_reduce_tc(x: jax.Array, *, agg: str, stride: int,
                       block_o: int = 8, block_c: int = 128,
-                      interpret: bool = True) -> jax.Array:
+                      interpret: bool = False) -> jax.Array:
     """x: [T, C] → [T//stride, C]; T % (block_o·stride) == 0, C % block_c == 0
     (ops.py pads). agg ∈ {max, min, sum}."""
     T, C = x.shape
@@ -60,7 +56,7 @@ def segment_reduce_tc(x: jax.Array, *, agg: str, stride: int,
                                lambda o, c: (o, c))],
         out_specs=pl.BlockSpec((block_o, block_c), lambda o, c: (o, c)),
         out_shape=jax.ShapeDtypeStruct((n_seg, C), x.dtype),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel")),
         interpret=interpret,
     )(x)

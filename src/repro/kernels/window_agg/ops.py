@@ -13,7 +13,7 @@ from repro.kernels.window_agg.kernel import INIT, segment_reduce_tc
 @functools.partial(jax.jit, static_argnames=("agg", "window", "stride",
                                              "interpret"))
 def window_aggregate(x: jax.Array, *, agg: str, window: int, stride: int,
-                     interpret: bool = True) -> jax.Array:
+                     interpret: bool = False) -> jax.Array:
     """x: [T, C] → [n_out, C] with out[o] = agg(x[o·stride : o·stride+window]).
 
     window must be a multiple of stride (the paper's queries are:
@@ -27,9 +27,11 @@ def window_aggregate(x: jax.Array, *, agg: str, window: int, stride: int,
     m = window // stride
     base = "sum" if agg == "mean" else agg
 
-    # pad T to a block multiple, C to the 128-lane register width
-    n_out_est = (T - window) // stride + 1
-    block_o, block_c = min(8, n_out_est), 128
+    # pad T to a block multiple, C to the 128-lane register width; the
+    # output block's rows stay a multiple of the 8-row sublane tile even
+    # on short series (padded segments hold the neutral fill and are
+    # never combined)
+    block_o, block_c = 8, 128
     pad_t = (-T) % (block_o * stride)
     pad_c = (-C) % block_c
     fill = INIT[base]

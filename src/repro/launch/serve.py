@@ -18,13 +18,21 @@ from repro.models import model as M
 from repro.train.serve_step import greedy_generate
 
 
-def serve_demo(arch: str, *, batch: int = 4, prompt_len: int = 64,
-               gen: int = 32, full: bool = False, seed: int = 0):
+def demo_inputs(arch: str, *, batch: int = 4, prompt_len: int = 64,
+                full: bool = False, seed: int = 0):
+    """The demo's config, random parameters and prompt batch (all from
+    ``seed``)."""
     cfg = get_arch(arch) if full else get_arch(arch).reduced()
     params = M.init_params(cfg, jax.random.PRNGKey(seed))
     bd = make_batch(cfg, prompt_len, batch, 0, seed)
     bd.pop("labels", None)
-    bd = {k: jnp.asarray(v) for k, v in bd.items()}
+    return cfg, params, {k: jnp.asarray(v) for k, v in bd.items()}
+
+
+def serve_demo(arch: str, *, batch: int = 4, prompt_len: int = 64,
+               gen: int = 32, full: bool = False, seed: int = 0):
+    cfg, params, bd = demo_inputs(arch, batch=batch, prompt_len=prompt_len,
+                                  full=full, seed=seed)
 
     t0 = time.perf_counter()
     toks, cache = greedy_generate(cfg, params, bd, steps=gen,

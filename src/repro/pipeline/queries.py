@@ -15,13 +15,14 @@ larger windows offload to the VDC path — the Pallas window_agg kernel
 from __future__ import annotations
 
 import dataclasses
-import time
-from typing import Dict, List, Optional
+import functools
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
 from repro.kernels.window_agg import window_aggregate
+from repro.kernels.window_agg.kernel import INIT
 from repro.pipeline.operators import WindowSpec, aggregate
 from repro.pipeline.service import ServiceConfig, StreamService
 from repro.pipeline.store import TimeSeriesStore
@@ -52,11 +53,48 @@ class OffloadDecision:
     reason: str
 
 
-class HybridExecutor:
-    """Runs a service's window either on the edge or on the VDC path."""
+# Rows of the 128-lane fold that one kernel segment reduces: the kernel
+# holds 8 segments (2 MiB of f32) per VMEM block, so a window of any
+# length streams through VMEM instead of landing in it whole.
+OFFLOAD_SEGMENT_ROWS = 512
 
-    def __init__(self, edge_budget: int = EDGE_WINDOW_BUDGET):
+
+@functools.partial(jax.jit, static_argnames=("agg", "interpret"))
+def offload_aggregate(values: jax.Array, *, agg: str,
+                      interpret: bool = False) -> jax.Array:
+    """One whole-range window aggregate on the VDC path.
+
+    Folds the 1-D range into the TPU's 128 lanes, reduces it with the
+    Pallas segment kernel in ``OFFLOAD_SEGMENT_ROWS``-row segments (one
+    window spanning every segment), then combines the 128 per-lane
+    partials. Returns a scalar f32."""
+    base = "sum" if agg == "mean" else agg
+    n = values.shape[0]
+    cols, seg_rows = 128, OFFLOAD_SEGMENT_ROWS
+    rows = -(-n // (cols * seg_rows)) * seg_rows
+    x2 = jnp.pad(values.astype(jnp.float32), (0, rows * cols - n),
+                 constant_values=INIT[base]).reshape(rows, cols)
+    lanes = window_aggregate(x2, agg=base, window=rows, stride=seg_rows,
+                             interpret=interpret)[0]        # [128]
+    if base == "max":
+        return jnp.max(lanes)
+    if base == "min":
+        return jnp.min(lanes)
+    total = jnp.sum(lanes)
+    return total / n if agg == "mean" else total
+
+
+class HybridExecutor:
+    """Runs a service's window either on the edge or on the VDC path.
+
+    ``interpret`` is the caller's choice for the VDC path's Pallas
+    kernel: compiled for the TPU by default, the Pallas interpreter
+    where the caller runs on a CPU."""
+
+    def __init__(self, edge_budget: int = EDGE_WINDOW_BUDGET, *,
+                 interpret: bool = False):
         self.edge_budget = edge_budget
+        self.interpret = interpret
         self.offloads = 0
         self.edge_runs = 0
 
@@ -67,30 +105,13 @@ class HybridExecutor:
         return OffloadDecision(True, n_records,
                                "window exceeds edge compute/RAM — VDC JIT")
 
-    def run_window(self, values: np.ndarray, agg: str, *,
-                   stride: Optional[int] = None) -> float:
+    def run_window(self, values, agg: str) -> float:
+        """Aggregate one window; ``values`` is a host or device array
+        (an offloaded window already on the device stays there)."""
         d = self.decide(len(values))
         if not d.offload:
             self.edge_runs += 1
-            return aggregate(values, agg)
+            return aggregate(np.asarray(values), agg)
         self.offloads += 1
-        # VDC path: fold the 1-D range into the TPU's 128 lanes so the
-        # Pallas segment kernel reduces rows in parallel, then combine the
-        # 128 per-lane partials.
-        from repro.kernels.window_agg.kernel import INIT
-        base = "sum" if agg == "mean" else agg
-        n = len(values)
-        cols = 128
-        rows = -(-n // cols)
-        fill = 0.0 if agg == "mean" else INIT[base]
-        x = np.full((rows * cols,), fill, np.float32)
-        x[:n] = values
-        x2 = jnp.asarray(x).reshape(rows, cols)
-        seg = window_aggregate(x2, agg=base, window=rows, stride=rows,
-                               interpret=True)[0]          # [128]
-        if agg == "max":
-            return float(jnp.max(seg))
-        if agg == "min":
-            return float(jnp.min(seg))
-        total = float(jnp.sum(seg))
-        return total / n if agg == "mean" else total
+        return float(offload_aggregate(jnp.asarray(values), agg=agg,
+                                       interpret=self.interpret))

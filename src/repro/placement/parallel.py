@@ -6,15 +6,17 @@ DES replay — each an independent, CPU-bound ``engine.run_plan`` call on
 one shared, already-driven fire trace. :class:`ParallelEvaluator` fans
 those calls across a persistent worker pool:
 
-* **fork start method** (Linux default): workers inherit the parent's
-  *driven* engine by address-space copy — no pickling, no re-drive; the
-  pool amortizes across every batch of the evaluator's lifetime.
-* **no fork** (spawn-only platforms): workers rebuild the engine from
-  the scenario's JSON ``ScenarioSpec`` (``spec=``) and pay one
-  functional drive each, once per pool lifetime.
-* **workers <= 1, no usable start method, or no spec to rebuild from**:
-  clean in-process fallback — the batch runs the base class's serial
+* **workers > 1**: workers are *spawned* and rebuild the engine from the
+  scenario's JSON ``ScenarioSpec`` (``spec=``), paying one functional
+  drive each, once per pool lifetime. Spawn, not fork: the parent may
+  hold the TPU (the fluid ensemble runs there), and a forked child
+  would inherit its multithreaded runtime. Workers never initialise a
+  JAX backend — the DES is host code — and every result carries a
+  check of that, so a worker that touched JAX fails the batch.
+* **workers <= 1**: no pool — the batch runs the base class's serial
   loop in the caller's process.
+
+A pool that cannot start or dies raises; nothing falls back silently.
 
 Determinism: ``run_plan`` is a pure function of (driven engine, plan),
 so per-plan results do not depend on which worker computes them. The
@@ -33,6 +35,8 @@ import multiprocessing as mp
 import os
 from typing import Dict, List, Optional, Sequence, Tuple
 
+from jax._src import xla_bridge
+
 from repro.placement.cosim import CoSimResult, CoSimulator
 from repro.placement.plan import PlacementPlan
 from repro.placement.search import Evaluator
@@ -42,17 +46,19 @@ from repro.placement.search import Evaluator
 _WORKER_ENGINE = None
 
 
-def _init_worker(engine, spec_dict) -> None:
+def _init_worker(spec_dict: Dict) -> None:
     global _WORKER_ENGINE
-    if engine is None:
-        from repro.scenario.spec import ScenarioSpec
-        engine = ScenarioSpec.from_dict(spec_dict).compile()
-        engine._ensure_driven()
+    from repro.scenario.spec import ScenarioSpec
+    engine = ScenarioSpec.from_dict(spec_dict).compile()
+    engine._ensure_driven()
     _WORKER_ENGINE = engine
 
 
-def _eval_plan(plan_dict: Dict) -> CoSimResult:
-    return _WORKER_ENGINE.run_plan(PlacementPlan.from_dict(plan_dict))
+def _eval_plan(plan_dict: Dict) -> Tuple[CoSimResult, bool]:
+    """One exact-DES replay, plus whether this process has initialised
+    a JAX backend (jax exposes that check only privately)."""
+    res = _WORKER_ENGINE.run_plan(PlacementPlan.from_dict(plan_dict))
+    return res, xla_bridge.backends_are_initialized()
 
 
 def default_workers() -> int:
@@ -74,15 +80,14 @@ class ParallelEvaluator(Evaluator):
     Parameters
     ----------
     cosim:
-        The driven scorer (a ``ScenarioEngine``) — also the engine
-        forked into workers.
+        The driven scorer (a ``ScenarioEngine``).
     workers:
         Pool width; ``None`` means :func:`default_workers`. ``<= 1``
         disables the pool entirely (serial in-process evaluation).
     spec:
-        Optional ``ScenarioSpec`` (or its ``to_dict()`` form) for
-        spawn-only platforms where workers cannot inherit the engine;
-        without it, no-fork platforms fall back to in-process serial.
+        The ``ScenarioSpec`` (or its ``to_dict()`` form) ``cosim`` was
+        compiled from; the workers rebuild their engines from it.
+        Required when ``workers > 1``.
     """
 
     def __init__(self, cosim: CoSimulator, workers: Optional[int] = None,
@@ -94,48 +99,22 @@ class ParallelEvaluator(Evaluator):
         self.workers = default_workers() if workers is None else int(workers)
         self._spec_dict = (spec.to_dict() if hasattr(spec, "to_dict")
                           else spec)
+        if self.workers > 1 and self._spec_dict is None:
+            raise ValueError("a worker pool rebuilds the engine from its "
+                             "ScenarioSpec: pass spec= (or workers=1)")
         self._pool = None
-        self._pool_broken = False
         self.parallel_batches = 0   # batches that actually used the pool
         self.parallel_jobs = 0      # plans evaluated by pool workers
         self.serial_jobs = 0        # uncached plans evaluated in-process
 
     # ------------------------------------------------------------- pool
-    def _start_method(self) -> Optional[str]:
-        methods = mp.get_all_start_methods()
-        if "fork" in methods:
-            return "fork"
-        if self._spec_dict is not None and methods:
-            return methods[0]
-        return None
-
     def _ensure_pool(self):
-        if self.workers <= 1 or self._pool_broken:
+        if self.workers <= 1:
             return None
-        if self._pool is not None:
-            return self._pool
-        method = self._start_method()
-        if method is None:
-            self._pool_broken = True
-            return None
-        try:
-            ctx = mp.get_context(method)
-            if method == "fork":
-                # fork inherits the driven engine through the address
-                # space — make sure the trace exists before forking so
-                # workers never each re-drive it
-                ensure = getattr(self.cosim, "_ensure_driven", None)
-                if ensure is not None:
-                    ensure()
-                initargs = (self.cosim, None)
-            else:
-                initargs = (None, self._spec_dict)
-            self._pool = ctx.Pool(processes=self.workers,
-                                  initializer=_init_worker,
-                                  initargs=initargs)
-        except Exception:
-            self._pool_broken = True
-            self._pool = None
+        if self._pool is None:
+            self._pool = mp.get_context("spawn").Pool(
+                processes=self.workers, initializer=_init_worker,
+                initargs=(self._spec_dict,))
         return self._pool
 
     def close(self) -> None:
@@ -174,19 +153,14 @@ class ParallelEvaluator(Evaluator):
         pool = self._ensure_pool() if len(todo) > 1 else None
         fresh: Dict[Tuple, CoSimResult] = {}
         if pool is not None:
-            try:
-                results = pool.map(_eval_plan,
-                                   [p.to_dict() for p in todo])
-            except Exception:
-                # a dead pool must not kill the search — evaluate the
-                # batch in-process and stop using the pool
-                self._pool_broken = True
-                self.close()
-                results = None
-            if results is not None:
-                self.parallel_batches += 1
-                self.parallel_jobs += len(todo)
-                fresh = {self._key(p): r for p, r in zip(todo, results)}
+            results = pool.map(_eval_plan, [p.to_dict() for p in todo])
+            if any(touched for _, touched in results):
+                raise RuntimeError("a pool worker initialised a JAX "
+                                   "backend; workers must stay off the "
+                                   "device the parent may hold")
+            self.parallel_batches += 1
+            self.parallel_jobs += len(todo)
+            fresh = {self._key(p): r for p, (r, _) in zip(todo, results)}
         out: List[CoSimResult] = []
         for plan in plans:
             key = self._key(plan)

@@ -2,9 +2,10 @@
 
 Scenario profiles used to *declare* per-service operator cost; this
 module *measures* it: the service's operator kernel (``window_agg``,
-``ssd_scan`` or ``flash_attention``) is dry-run in interpret mode on a
-canonical shape derived from the service's window, and XLA's compiled
-cost analysis reports the FLOP count, normalized per ingested record.
+``ssd_scan`` or ``flash_attention``) is compiled on a canonical shape
+derived from the service's window — for the TPU, or in the Pallas
+interpreter where the caller asks for it — and XLA's compiled cost
+analysis reports the FLOP count, normalized per ingested record.
 That number feeds the same roofline cost cells
 (:func:`repro.scenario.engine.analytics_cost_model`) the DC simulator
 prices VDC steps with — closing the ROADMAP item "learn per-service
@@ -12,11 +13,11 @@ flops_per_record from measured kernel dry-runs".
 
 When XLA cannot cost the program (backend without cost analysis), a
 documented analytic fallback keeps calibration deterministic and
-dependency-free.
+dependency-free. A kernel that fails to lower or compile raises.
 
 Usage::
 
-    cal = KernelCalibrator()
+    cal = KernelCalibrator()                   # interpret=True on a CPU
     engine = spec.compile(calibrator=cal)      # measured profiles
     print(cal.report())                        # what was measured
 
@@ -52,14 +53,10 @@ class Calibration:
 
 def _cost_flops(jitted, *args) -> Optional[float]:
     """FLOPs of a compiled program via XLA cost analysis (None when the
-    backend does not expose one). Tracing/lowering errors propagate —
-    a kernel that cannot lower for the requested shape/agg is a real
-    calibration bug, not a missing-cost-analysis backend."""
-    lowered = jitted.lower(*args)
-    try:
-        ca = lowered.compile().cost_analysis()
-    except Exception:
-        return None
+    backend does not expose one). Tracing, lowering and compile errors
+    propagate — a kernel the compiler refuses for the requested shape
+    is a real calibration bug, not a missing-cost-analysis backend."""
+    ca = jitted.lower(*args).compile().cost_analysis()
     if isinstance(ca, (list, tuple)):
         ca = ca[0] if ca else None
     if not ca:
@@ -73,11 +70,12 @@ class KernelCalibrator:
 
     Callable with a :class:`~repro.scenario.spec.ServiceSpec` so it can
     be passed straight to ``ScenarioSpec.compile(calibrator=...)``.
-    ``interpret=True`` runs the Pallas kernels in interpreter mode —
-    fine for cost analysis, which reads the lowered program, not the
+    The kernels compile for the TPU unless the caller passes
+    ``interpret=True`` (the Pallas interpreter, which a CPU needs) —
+    fine for cost analysis, which reads the compiled program, not the
     wall clock."""
 
-    def __init__(self, interpret: bool = True, stride: int = 64):
+    def __init__(self, interpret: bool = False, stride: int = 64):
         self.interpret = interpret
         self.stride = stride
         self._cache: Dict[Tuple[str, str, int], Calibration] = {}

@@ -20,12 +20,8 @@ sources produce byte-compatible epoch records.
 from __future__ import annotations
 
 import dataclasses
-from typing import Dict, List, Optional, Sequence, Tuple
-
-try:                                     # py3.8+: typing.Protocol
-    from typing import Protocol, runtime_checkable
-except ImportError:                      # pragma: no cover
-    Protocol, runtime_checkable = object, (lambda c: c)
+from typing import (Dict, List, Optional, Protocol, Sequence, Tuple,
+                    runtime_checkable)
 
 from repro.core.costmodel import CostModel
 from repro.online.fleet import FleetSpec

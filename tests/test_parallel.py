@@ -1,6 +1,7 @@
 """Parallel + incremental planning hot path: ParallelEvaluator
 bit-equality with the serial evaluator (any worker count, repeated
-runs, broken-pool and in-process fallbacks), delta-aware block
+runs, the in-process path, pools that fail loudly and workers that
+stay off JAX), delta-aware block
 screening vs the dense screen, cross-epoch evaluator-cache reuse (the
 online controller's telemetry counters), and the sealed-plan
 regression (mutating a plan after ``key()`` must raise)."""
@@ -87,19 +88,48 @@ def test_parallel_in_process_fallback(small_hier):
     assert [r.vos for r in got] == [ser(p).vos for p in plans]
 
 
-def test_parallel_broken_pool_falls_back_serial(small_hier):
-    """A pool that cannot start (or died) degrades to in-process
-    evaluation with identical results."""
+def test_parallel_pool_needs_spec(small_hier):
+    """Spawned workers rebuild the engine from the spec: a pool without
+    one is refused up front instead of degrading to serial."""
     _, eng = small_hier
+    with pytest.raises(ValueError, match="spec"):
+        ParallelEvaluator(eng, workers=2)
+
+
+def test_parallel_dead_pool_raises(small_hier):
+    """A pool that died fails the batch; it never falls back silently."""
+    spec, eng = small_hier
     names = list(eng.topology)
     plans = [PlacementPlan.all_dc(names, chips=c, dvfs_f=1.0)
              for c in (4, 8)]
-    pev = ParallelEvaluator(eng, workers=2)
-    pev._pool_broken = True
-    got = pev.evaluate_batch(plans)
-    assert pev.serial_jobs == len(plans) and pev.parallel_jobs == 0
-    ser = Evaluator(eng)
-    assert [r.vos for r in got] == [ser(p).vos for p in plans]
+    with ParallelEvaluator(eng, workers=2, spec=spec) as pev:
+        pev._ensure_pool().terminate()
+        with pytest.raises(ValueError, match="not running"):
+            pev.evaluate_batch(plans)
+    assert pev.serial_jobs == 0 and pev.parallel_jobs == 0
+
+
+def test_parallel_workers_stay_off_jax(small_hier):
+    """The pool serves a batch and its workers report no JAX backend,
+    while the same check in a process that has touched JAX trips."""
+    import jax
+
+    from repro.placement import parallel
+
+    spec, eng = small_hier
+    names = list(eng.topology)
+    plans = [PlacementPlan.all_dc(names, chips=c, dvfs_f=1.0)
+             for c in (4, 8, 16)]
+    with ParallelEvaluator(eng, workers=2, spec=spec) as pev:
+        got = pev.evaluate_batch(plans)
+    assert pev.parallel_batches == 1 and pev.parallel_jobs == len(plans)
+    assert [r.vos for r in got] == [Evaluator(eng)(p).vos for p in plans]
+    jax.devices()
+    parallel._WORKER_ENGINE = eng
+    try:
+        assert parallel._eval_plan(plans[0].to_dict())[1] is True
+    finally:
+        parallel._WORKER_ENGINE = None
 
 
 def test_parallel_batch_cache_bookkeeping(small_hier):

@@ -139,7 +139,7 @@ def test_buffer_evictions_counter_accumulates_across_fetches():
 
 
 def test_offload_decision_boundary():
-    hx = HybridExecutor(edge_budget=1000)
+    hx = HybridExecutor(edge_budget=1000, interpret=True)
     assert not hx.decide(1000).offload
     assert hx.decide(1001).offload
     big = np.random.default_rng(0).standard_normal(4096).astype(np.float32)

@@ -187,7 +187,7 @@ def test_compile_requires_flops_or_calibrator():
 
 # ------------------------------------------------------------- calibration
 def test_kernel_calibrator_measures_and_caches():
-    cal = KernelCalibrator()
+    cal = KernelCalibrator(interpret=True)
     c1 = cal.measure("window_agg", agg="max", m=2)
     c2 = cal.measure("window_agg", agg="max", m=2)
     assert c1 is c2                       # cached
@@ -195,15 +195,46 @@ def test_kernel_calibrator_measures_and_caches():
     assert c1.source in ("xla-cost-analysis", "analytic")
     assert len(cal.log) == 1
     # deterministic across instances
-    assert (KernelCalibrator().measure("window_agg", agg="max", m=2)
+    assert (KernelCalibrator(interpret=True).measure("window_agg", agg="max", m=2)
             .flops_per_record == pytest.approx(c1.flops_per_record))
     with pytest.raises(ValueError, match="unknown operator"):
         cal.measure("not_a_kernel")
 
 
+def test_kernel_calibrator_compile_errors_raise():
+    """Regression: a kernel the compiler refuses must raise, not turn
+    quietly into the analytic fallback; only a backend without a cost
+    analysis falls back."""
+    from repro.scenario.calibrate import _cost_flops
+
+    class _Program:
+        def __init__(self, cost=None, error=None):
+            self.cost, self.error = cost, error
+
+        def lower(self, *args):
+            return self
+
+        def compile(self):
+            if self.error is not None:
+                raise self.error
+            return self
+
+        def cost_analysis(self):
+            return self.cost
+
+    with pytest.raises(RuntimeError, match="vmem"):
+        _cost_flops(_Program(error=RuntimeError("Ran out of memory in "
+                                                "memory space vmem")))
+    assert _cost_flops(_Program(cost=None)) is None
+    assert _cost_flops(_Program(cost=[{"flops": 8.0}])) == 8.0
+    # compiled mode on a CPU cannot lower a Pallas kernel: a loud error
+    with pytest.raises(ValueError, match="interpret"):
+        KernelCalibrator().measure("window_agg", agg="max", m=2)
+
+
 def test_calibrated_compile_uses_measured_flops():
     spec = _mini_spec(horizon=120.0)
-    cal = KernelCalibrator()
+    cal = KernelCalibrator(interpret=True)
     engine = spec.compile(calibrator=cal)
     for name in ("agg", "smooth"):
         svc = next(s for s in spec.services if s.name == name)
