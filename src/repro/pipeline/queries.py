@@ -27,6 +27,7 @@ from repro.pipeline.operators import WindowSpec, aggregate
 from repro.pipeline.service import ServiceConfig, StreamService
 from repro.pipeline.store import TimeSeriesStore
 from repro.pipeline.streams import Broker
+from repro.utils.tracing import span
 
 EDGE_WINDOW_BUDGET = 100_000  # records an edge service may aggregate inline
 
@@ -50,7 +51,6 @@ def neubot_query_2(broker: Broker, store: TimeSeriesStore) -> StreamService:
 class OffloadDecision:
     offload: bool
     n_records: int
-    reason: str
 
 
 # Rows of the 128-lane fold that one kernel segment reduces: the kernel
@@ -99,19 +99,25 @@ class HybridExecutor:
         self.edge_runs = 0
 
     def decide(self, n_records: int) -> OffloadDecision:
-        if n_records <= self.edge_budget:
-            return OffloadDecision(False, n_records,
-                                   f"fits edge budget ({self.edge_budget})")
-        return OffloadDecision(True, n_records,
-                               "window exceeds edge compute/RAM — VDC JIT")
+        return OffloadDecision(n_records > self.edge_budget, n_records)
 
     def run_window(self, values, agg: str) -> float:
         """Aggregate one window; ``values`` is a host or device array
-        (an offloaded window already on the device stays there)."""
+        (an offloaded window already on the device stays there).
+
+        With tracing on, an offloaded window leaves the span
+        ``repro.offload.run_window`` holding ``repro.offload.launch``
+        (until the unready result comes back) and then
+        ``repro.offload.fetch`` (the wait for the result and its copy
+        to the host)."""
         d = self.decide(len(values))
         if not d.offload:
             self.edge_runs += 1
             return aggregate(np.asarray(values), agg)
         self.offloads += 1
-        return float(offload_aggregate(jnp.asarray(values), agg=agg,
-                                       interpret=self.interpret))
+        with span("repro.offload.run_window"):
+            with span("repro.offload.launch"):
+                out = offload_aggregate(jnp.asarray(values), agg=agg,
+                                        interpret=self.interpret)
+            with span("repro.offload.fetch"):
+                return float(out)
