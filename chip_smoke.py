@@ -11,7 +11,8 @@ reference:
              device is a TPU; prints the device and the versions.
 1. offload — the paper's Neubot Q2 (a 120-day mean at 1 Hz) through
              ``HybridExecutor.run_window`` for 8 things' histories held
-             on the device, ``mean`` and ``max``, against numpy float64.
+             on the device, ``mean`` and ``max``, against numpy float64;
+             every result must be one the program wrote to host memory.
 2. kernels — ``ssd_scan`` at mamba2-1.3b widths and ``flash_attention``
              at smollm-135m widths against their ``ref.py`` run in
              float32 on the CPU device.
@@ -118,9 +119,13 @@ def phase_offload(key):
     check(hx.offloads == 2 * things and hx.edge_runs == 0,
           f"every window offloads ({hx.offloads} offloads, "
           f"{hx.edge_runs} edge runs)")
+    check(hx.host_results == hx.offloads,
+          f"every offloaded result lands in host memory "
+          f"({hx.host_results} of {hx.offloads})")
     print(f"[offload] {things} things x {n} records: max exact, mean worst "
           f"rel err {worst:.3e} (limit {MEAN_RTOL:g}); "
-          f"{hx.offloads} offloads")
+          f"{hx.offloads} offloads, {hx.host_results} results in host "
+          f"memory")
 
 
 # ------------------------------------------------------------- phase 2

@@ -11,6 +11,7 @@ The topology is described inside a fixture, never at import: only one
 process at a time may load the TPU library.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -41,6 +42,7 @@ def _compile(fn, sharding, *shapes):
     args = [jax.ShapeDtypeStruct(s, d, sharding=sharding) for s, d in shapes]
     hlo = jax.jit(fn).lower(*args).compile().as_text()
     assert "tpu_custom_call" in hlo
+    return hlo
 
 
 F32, BF16 = jnp.float32, jnp.bfloat16
@@ -52,6 +54,20 @@ def test_q2_offload_compiles(one_chip, agg):
     through VMEM in row segments instead of one window-sized block."""
     _compile(lambda x: offload_aggregate(x, agg=agg), one_chip,
              ((Q2_RECORDS,), F32))
+
+
+@pytest.mark.parametrize("agg", ["mean", "max"])
+def test_q2_offload_result_in_host_memory(one_chip, agg):
+    """With ``result_on_host`` the program's own last step copies the
+    scalar to host memory (memory space 5 in the entry's result
+    layout), and the Pallas kernel stays in the program."""
+    hlo = _compile(lambda x: offload_aggregate(x, agg=agg,
+                                               result_on_host=True),
+                   one_chip, ((Q2_RECORDS,), F32))
+    layout = re.search(r"entry_computation_layout=\{\((.*?)\)->(.*?)\}\}",
+                       hlo)
+    assert layout and "S(5)" in layout.group(2)
+    assert "copy-done" in hlo
 
 
 @pytest.mark.parametrize("T,C,window,stride", [

@@ -148,6 +148,72 @@ def test_offload_decision_boundary():
     assert hx.offloads == 1
 
 
+@pytest.mark.parametrize("agg", ["mean", "max"])
+def test_offload_result_stays_on_device_off_the_tpu(agg):
+    """On a CPU, in interpret mode, the program returns its result as
+    before: same value as ``offload_aggregate``'s default, and no
+    window counted as written to host memory."""
+    import jax.numpy as jnp
+    from repro.pipeline.queries import offload_aggregate
+
+    hx = HybridExecutor(edge_budget=1000, interpret=True)
+    big = np.random.default_rng(1).standard_normal(4096).astype(np.float32)
+    v = hx.run_window(big, agg)
+    assert v == float(offload_aggregate(jnp.asarray(big), agg=agg,
+                                        interpret=True))
+    assert hx.offloads == 1 and hx.host_results == 0
+    assert not hx.result_on_host(jnp.asarray(big).device)
+
+
+class _Device:
+    """A device stub that counts how often its platform is read."""
+
+    def __init__(self, platform):
+        self._platform, self.reads = platform, 0
+
+    @property
+    def platform(self):
+        self.reads += 1
+        return self._platform
+
+
+def test_result_on_host_decided_once_per_device():
+    hx = HybridExecutor()
+    tpu, cpu = _Device("tpu"), _Device("cpu")
+    assert hx.result_on_host(tpu) and hx.result_on_host(tpu)
+    assert not hx.result_on_host(cpu) and not hx.result_on_host(cpu)
+    assert tpu.reads == 1 and cpu.reads == 1
+    assert not HybridExecutor(interpret=True).result_on_host(_Device("tpu"))
+
+
+def test_executor_asks_a_tpu_for_the_host_result(monkeypatch):
+    """Where the window's device reports a TPU, the executor asks the
+    program for its result in host memory, reads it in place and counts
+    it. (A CPU array's buffer is host memory too, so the read is real.)"""
+    import types
+
+    import jax.numpy as jnp
+
+    from repro.pipeline import queries
+
+    tpu = _Device("tpu")
+    window = types.SimpleNamespace(device=tpu)
+    asked = []
+
+    def program(x, *, agg, interpret=False, result_on_host=False):
+        asked.append(result_on_host)
+        return jnp.float32(2.5)
+
+    monkeypatch.setattr(queries, "jnp",
+                        types.SimpleNamespace(asarray=lambda v: window))
+    monkeypatch.setattr(queries, "offload_aggregate", program)
+    hx = HybridExecutor(edge_budget=1000)
+    big = np.zeros(4096, np.float32)
+    assert [hx.run_window(big, "mean") for _ in range(2)] == [2.5, 2.5]
+    assert asked == [True, True] and tpu.reads == 1
+    assert hx.host_results == hx.offloads == 2
+
+
 def test_kmeans_and_linreg_services():
     import jax.numpy as jnp
     rng = np.random.default_rng(0)
