@@ -101,6 +101,13 @@ def offload_aggregate(values: jax.Array, *, agg: str,
 class HybridExecutor:
     """Runs a service's window either on the edge or on the VDC path.
 
+    A window of at most ``edge_budget`` records (the paper's Q1, 180
+    records at 1 Hz) takes the edge branch: numpy on the host, counted
+    in ``edge_runs`` and, with tracing on, marked by the span
+    ``repro.edge.run_window``. A larger one (Q2, 10,368,000 records) is
+    offloaded, counted in ``offloads`` and marked by the
+    ``repro.offload.*`` spans (``run_window``).
+
     ``interpret`` is the caller's choice for the VDC path's Pallas
     kernel: compiled for the TPU by default, the Pallas interpreter
     where the caller runs on a CPU. ``host_results`` counts the
@@ -140,7 +147,8 @@ class HybridExecutor:
         host waits for the program's completion and for no transfer of
         the scalar after it. Elsewhere ``float`` fetches the result.
 
-        With tracing on, an offloaded window leaves the span
+        With tracing on, an edge window leaves the span
+        ``repro.edge.run_window``, and an offloaded window the span
         ``repro.offload.run_window`` holding ``repro.offload.launch``
         (until the unready result comes back) and then
         ``repro.offload.fetch`` (the wait for the result and its read
@@ -148,7 +156,8 @@ class HybridExecutor:
         d = self.decide(len(values))
         if not d.offload:
             self.edge_runs += 1
-            return aggregate(np.asarray(values), agg)
+            with span("repro.edge.run_window"):
+                return aggregate(np.asarray(values), agg)
         self.offloads += 1
         with span("repro.offload.run_window"):
             with span("repro.offload.launch"):

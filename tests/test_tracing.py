@@ -57,10 +57,12 @@ def test_spans_off_are_one_shared_noop_and_leave_no_event(tmp_path,
                                                           spans_off):
     assert tracing.span("a") is tracing.span("b", k=1)
     hx = HybridExecutor(edge_budget=0, interpret=True)
+    edge = HybridExecutor(edge_budget=10_000, interpret=True)
     x = _window(4093)   # a new shape: the call lowers a program
-    events = _profile(tmp_path, lambda: hx.run_window(x, "max"))
+    events = _profile(tmp_path, lambda: (hx.run_window(x, "max"),
+                                         edge.run_window(x, "max")))
     assert events == []
-    assert hx.offloads == 1
+    assert hx.offloads == 1 and edge.edge_runs == 1
 
 
 def test_offloaded_window_nests_launch_then_fetch(tmp_path, spans_on):
@@ -78,11 +80,15 @@ def test_offloaded_window_nests_launch_then_fetch(tmp_path, spans_on):
     assert ws <= ls <= le <= fs <= fe <= we
 
 
-def test_edge_window_has_no_span(tmp_path, spans_on):
+def test_edge_window_has_its_span(tmp_path, spans_on):
     hx = HybridExecutor(edge_budget=10_000, interpret=True)
     x = _window(4089)
-    events = _profile(tmp_path, lambda: hx.run_window(x, "max"))
-    assert events == [] and hx.edge_runs == 1
+    got = []
+    events = _profile(tmp_path, lambda: got.append(hx.run_window(x, "max")))
+    assert got == [float(x.max())]
+    assert [e[0] for e in events] == ["repro.edge.run_window"]
+    assert events[0][3] == {}
+    assert hx.edge_runs == 1 and hx.offloads == 0
 
 
 def test_new_shape_lowers_once_and_warm_call_not_at_all(tmp_path,
