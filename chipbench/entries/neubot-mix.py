@@ -6,7 +6,8 @@ in one transfer and lands it in every thing's ring, in place; then
 each thing's Q1 window (its last 3 minutes, a view of the edge's host
 stream) goes through ``HybridExecutor.run_window``'s edge branch. On
 every fifth round Q2's slide is due too, and each thing's 120-day ring
-goes through the same executor's offload path, as in ``q2-offload``.
+goes through the same executor's offload path, handed over in one
+``run_windows`` call, as in ``q2-offload``.
 Work runs in due-time order, one piece after another: a round that
 comes due while a slide drains waits for it. A window's latency runs
 from its round's due time to its result on the host. ``run.records``
@@ -19,7 +20,6 @@ Q1 in its group ``q1``.
 """
 from __future__ import annotations
 
-import contextlib
 import functools
 import math
 import sys
@@ -32,7 +32,6 @@ import neubot_data as data
 from harness import Check, HostWatch, entry_of
 
 _q2 = entry_of({"entry": "q2-offload"})
-_NO_SPAN = contextlib.nullcontext()
 
 
 def _shape(run):
@@ -98,18 +97,16 @@ def setup(run) -> None:
                      edge_runs0=hx.edge_runs)
 
 
-def _windows(run, hx, views, agg, due, lat, vals, span=None):
-    """Run one window per thing through ``hx``, each in the benchmark's
-    span ``span`` where one is named; each one's latency from ``due``
-    and its value land in ``lat`` and ``vals``. Returns the longest
-    window's seconds and the clock at the last result."""
+def _windows(run, hx, views, agg, due, lat, vals):
+    """Run one Q1 window per thing through ``hx``; each one's latency
+    from ``due`` and its value land in ``lat`` and ``vals``. Returns the
+    longest window's seconds and the clock at the last result."""
     longest, done = 0.0, 0.0
     for i, x in enumerate(views):
         run.attempted += 1
         start = time.perf_counter()
         try:
-            with run.span(span) if span else _NO_SPAN:
-                v = hx.run_window(x, agg)
+            v = hx.run_window(x, agg)
         except Exception as e:  # a window that fails is counted
             run.failed += 1
             print(f"mix: {agg} window of thing {i} failed: {e!r}",
@@ -165,9 +162,8 @@ def window(run) -> None:
             if r % every == every - 1:
                 k = r // every
                 t2 = time.perf_counter()
-                slow, last2 = _windows(
-                    run, hx, hist, cfg["agg"], due, q2_lat[k],
-                    q2_val[k], span="bench.q2.run_window")
+                slow, last2 = _q2.run_slide(
+                    run, hx, hist, cfg["agg"], due, q2_lat[k], q2_val[k])
                 drains.append(time.perf_counter() - t2)
                 longest, last = max(longest, slow), max(last, last2)
             done = max(done, last)

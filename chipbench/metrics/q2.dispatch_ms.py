@@ -1,5 +1,6 @@
 """Host time per window, in ms: the mean length of the benchmark's span
-around ``run_window`` (which ends with the result on the host) less
+around a window's handover (a ``run_window`` call, or one ``next()`` of
+``run_windows``; either ends with the result on the host) less
 the device time of the ``offload_aggregate`` program per window. Both
 come from the trace; the difference does not depend on how the host's
 and the device's clocks line up."""

@@ -79,12 +79,16 @@ def _offload_nan(values, agg, **kw):
 
 @pytest.mark.parametrize("fault", ["edge_answer_altered", "q1_offloaded",
                                    "uplink_dropped", "edge_answer_nan",
-                                   "offload_answer_nan"])
-def test_broken_timed_path_is_not_correct(fault, monkeypatch):
+                                   "offload_answer_nan", "slide_one_altered"])
+def test_broken_timed_path_is_not_correct(fault, monkeypatch,
+                                          slide_one_altered):
     from repro.pipeline import queries
     import harness
     kw = {}
-    if fault in ("edge_answer_altered", "edge_answer_nan"):
+    if fault == "slide_one_altered":
+        monkeypatch.setattr(queries.HybridExecutor, "run_windows",
+                            slide_one_altered(1.001), raising=False)
+    elif fault in ("edge_answer_altered", "edge_answer_nan"):
         factor = 1 + 1e-6 if fault == "edge_answer_altered" else float("nan")
         monkeypatch.setattr(queries, "aggregate",
                             _edge_altered(queries.aggregate, factor))
@@ -108,4 +112,5 @@ def test_broken_timed_path_is_not_correct(fault, monkeypatch):
                       "q1_offloaded": {"q1_windows_not_at_edge"},
                       "uplink_dropped": {"q2_mean_max_rel_err"},
                       "edge_answer_nan": {"q1_max_mismatches"},
-                      "offload_answer_nan": {"q2_mean_max_rel_err"}}[fault]
+                      "offload_answer_nan": {"q2_mean_max_rel_err"},
+                      "slide_one_altered": {"q2_mean_max_rel_err"}}[fault]

@@ -61,14 +61,24 @@ def _one_thing_altered(orig, things=8):
 
 
 @pytest.mark.parametrize("fault", ["answer_altered", "half_left_out",
-                                   "state_unchanged", "one_thing_altered"])
-def test_broken_timed_path_is_not_correct(fault, monkeypatch):
+                                   "state_unchanged", "one_thing_altered",
+                                   "slide_one_altered", "slide_one_nan"])
+def test_broken_timed_path_is_not_correct(fault, monkeypatch,
+                                          slide_one_altered):
     from repro.pipeline import queries
     import harness
     if fault == "one_thing_altered":
+        # an executor with no run_windows: the entry's stand-in for it
+        # calls run_window a window
+        monkeypatch.delattr(queries.HybridExecutor, "run_windows",
+                            raising=False)
         orig = queries.HybridExecutor.run_window
         monkeypatch.setattr(queries.HybridExecutor, "run_window",
                             _one_thing_altered(orig))
+    elif fault in ("slide_one_altered", "slide_one_nan"):
+        factor = 1.001 if fault == "slide_one_altered" else float("nan")
+        monkeypatch.setattr(queries.HybridExecutor, "run_windows",
+                            slide_one_altered(factor), raising=False)
     elif fault == "answer_altered":
         monkeypatch.setattr(queries, "offload_aggregate",
                             _altered(queries.offload_aggregate))
