@@ -17,6 +17,7 @@ from __future__ import annotations
 import ctypes
 import dataclasses
 import functools
+from typing import Iterator
 
 import jax
 import jax.numpy as jnp
@@ -106,13 +107,16 @@ class HybridExecutor:
     in ``edge_runs`` and, with tracing on, marked by the span
     ``repro.edge.run_window``. A larger one (Q2, 10,368,000 records) is
     offloaded, counted in ``offloads`` and marked by the
-    ``repro.offload.*`` spans (``run_window``).
+    ``repro.offload.*`` spans (``run_window``, or ``run_windows`` for a
+    list of windows handed over in one call).
 
     ``interpret`` is the caller's choice for the VDC path's Pallas
     kernel: compiled for the TPU by default, the Pallas interpreter
     where the caller runs on a CPU. ``host_results`` counts the
     offloaded windows whose program wrote its result to host memory
-    (every one on a TPU, none elsewhere)."""
+    (every one on a TPU, none elsewhere); ``offload_waits`` counts the
+    times the host waited for offloaded results, so ``offloads /
+    offload_waits`` is the windows a wait."""
 
     def __init__(self, edge_budget: int = EDGE_WINDOW_BUDGET, *,
                  interpret: bool = False):
@@ -120,6 +124,7 @@ class HybridExecutor:
         self.interpret = interpret
         self.offloads = 0
         self.host_results = 0
+        self.offload_waits = 0
         self.edge_runs = 0
         self._on_host: dict = {}
 
@@ -158,28 +163,76 @@ class HybridExecutor:
             self.edge_runs += 1
             with span("repro.edge.run_window"):
                 return aggregate(np.asarray(values), agg)
-        self.offloads += 1
         with span("repro.offload.run_window"):
             with span("repro.offload.launch"):
-                # jnp.asarray of a device array alone costs ~0.07 ms a
-                # call on a TPU v5e host
-                x = (values if isinstance(values, jax.Array)
-                     else jnp.asarray(values))
-                on_host = self.result_on_host(x.device)
-                out = (offload_aggregate(x, agg=agg, result_on_host=True)
-                       if on_host else
-                       offload_aggregate(x, agg=agg, interpret=self.interpret))
+                out, read = self._launch(values, agg)
             with span("repro.offload.fetch"):
-                if not on_host:
-                    return float(out)
-                self.host_results += 1
-                return _read_host_scalar(out)
+                self.offload_waits += 1
+                out.block_until_ready()
+                return read(out)
+
+    def run_windows(self, windows, agg: str) -> Iterator[float]:
+        """Aggregate a list of windows, each routed as ``run_window``
+        routes it and giving the same value: an iterator of one float a
+        window, in the order given.
+
+        At the first ``next()`` the call launches every offloaded window
+        (an edge window runs inline, in its turn), waits for the device
+        once, then reads each result from host memory, so a slide of
+        windows costs one wait and not one a window. If a launch raises,
+        the windows before it are yielded, the exception propagates and
+        no later window is launched.
+
+        With tracing on, the call leaves the span
+        ``repro.offload.run_windows`` holding one ``repro.offload.launch``
+        (every launch, and the edge windows' ``repro.edge.run_window``)
+        and then one ``repro.offload.fetch`` (the wait and the reads)."""
+        results, launched, error = [], [], None
+        with span("repro.offload.run_windows"):
+            with span("repro.offload.launch"):
+                try:
+                    for values in windows:
+                        if self.decide(len(values)).offload:
+                            out, read = self._launch(values, agg)
+                            launched.append(out)
+                            results.append((out, read))
+                            continue
+                        self.edge_runs += 1
+                        with span("repro.edge.run_window"):
+                            results.append(
+                                (aggregate(np.asarray(values), agg), None))
+                except Exception as e:  # yield the windows launched first
+                    error = e
+            with span("repro.offload.fetch"):
+                if launched:
+                    self.offload_waits += 1
+                    jax.block_until_ready(launched)
+                got = [read(v) if read else v for v, read in results]
+        yield from got
+        if error is not None:
+            raise error
+
+    def _launch(self, values, agg: str):
+        """Start one offloaded window's program. Returns its unready
+        result and the function that reads it once the program is
+        done."""
+        self.offloads += 1
+        # jnp.asarray of a device array alone costs ~0.07 ms a call on a
+        # TPU v5e host
+        x = values if isinstance(values, jax.Array) else jnp.asarray(values)
+        if self.result_on_host(x.device):
+            self.host_results += 1
+            return (offload_aggregate(x, agg=agg, result_on_host=True),
+                    _read_host_scalar)
+        return (offload_aggregate(x, agg=agg, interpret=self.interpret),
+                float)
 
 
 def _read_host_scalar(out: jax.Array) -> float:
-    """The f32 scalar of ``out``, a result in host memory, read where it
-    lies once its program is done: one load from memory, where
+    """The f32 scalar of ``out``, a result in host memory whose program
+    is done, read where it lies: one load from memory, where
     ``float(out)`` routes even a host-memory result through the
-    runtime's transfer path (~0.15 ms a call on a TPU v5e host)."""
-    out.block_until_ready()
+    runtime's transfer path (~0.15 ms a call on a TPU v5e host). The
+    caller waits for the program first; an unready result's memory
+    holds no value yet."""
     return ctypes.c_float.from_address(out.unsafe_buffer_pointer()).value

@@ -214,6 +214,108 @@ def test_executor_asks_a_tpu_for_the_host_result(monkeypatch):
     assert hx.host_results == hx.offloads == 2
 
 
+def _mixed_windows():
+    """Offloaded windows of two lengths around one edge-sized window,
+    for an executor whose ``edge_budget`` is 1000."""
+    rng = np.random.default_rng(2)
+    return [rng.standard_normal(n).astype(np.float32)
+            for n in (4096, 5000, 700, 4096, 5000)]
+
+
+@pytest.mark.parametrize("agg", ["mean", "max"])
+def test_run_windows_yields_run_window_values_in_order(agg):
+    ws = _mixed_windows()
+    one = HybridExecutor(edge_budget=1000, interpret=True)
+    want = [one.run_window(w, agg) for w in ws]
+    hx = HybridExecutor(edge_budget=1000, interpret=True)
+    got = list(hx.run_windows(ws, agg))
+    assert got == want      # bit for bit, edge window included
+    for h in (one, hx):
+        assert (h.offloads, h.edge_runs, h.host_results) == (4, 1, 0)
+    assert one.offload_waits == 4 and hx.offload_waits == 1
+
+
+@pytest.mark.parametrize("sizes, offloads, edge_runs, waits", [
+    ((4096, 700, 5000), 2, 1, 1),
+    ((4096,), 1, 0, 1),
+    ((700, 10), 0, 2, 0),
+    ((), 0, 0, 0),
+])
+def test_run_windows_waits_once_a_call_that_offloads(sizes, offloads,
+                                                     edge_runs, waits):
+    hx = HybridExecutor(edge_budget=1000, interpret=True)
+    ws = [np.ones(n, np.float32) for n in sizes]
+    assert list(hx.run_windows(ws, "sum")) == [float(n) for n in sizes]
+    assert (hx.offloads, hx.edge_runs, hx.offload_waits) == (
+        offloads, edge_runs, waits)
+    assert list(hx.run_windows(ws, "sum")) == [float(n) for n in sizes]
+    assert hx.offload_waits == 2 * waits
+
+
+@pytest.mark.parametrize("j", [0, 1, 3])
+def test_run_windows_launch_raising_at_j_yields_those_before(j,
+                                                             monkeypatch):
+    from repro.pipeline import queries
+
+    ws = [w for w in _mixed_windows() if len(w) > 1000]
+    want = [HybridExecutor(edge_budget=1000, interpret=True)
+            .run_window(w, "mean") for w in ws]
+    real, launched = queries.offload_aggregate, []
+
+    def program(x, **kw):
+        launched.append(x)
+        if len(launched) == j + 1:
+            raise RuntimeError(f"launch {j} failed")
+        return real(x, **kw)
+
+    monkeypatch.setattr(queries, "offload_aggregate", program)
+    hx = HybridExecutor(edge_budget=1000, interpret=True)
+    got = []
+    with pytest.raises(RuntimeError, match=f"launch {j} failed"):
+        for v in hx.run_windows(ws, "mean"):
+            got.append(v)
+    assert got == want[:j]
+    assert len(launched) == j + 1      # nothing launched after window j
+    assert hx.offload_waits == (1 if j else 0)
+
+
+def test_run_windows_asks_a_tpu_for_host_results_and_waits_once(
+        monkeypatch):
+    """On a (stubbed) TPU every window of the call asks the program for
+    its result in host memory, the call waits for the device once, and
+    each value is read in place in the order given."""
+    import types
+
+    import jax
+    import jax.numpy as jnp
+
+    from repro.pipeline import queries
+
+    tpu = _Device("tpu")
+    window = types.SimpleNamespace(device=tpu)
+    asked, waits = [], []
+
+    def program(x, *, agg, interpret=False, result_on_host=False):
+        asked.append(result_on_host)
+        return jnp.float32(len(asked))
+
+    def block_until_ready(x):
+        waits.append(len(x))
+        return real_wait(x)
+
+    real_wait = jax.block_until_ready
+    monkeypatch.setattr(queries, "jnp",
+                        types.SimpleNamespace(asarray=lambda v: window))
+    monkeypatch.setattr(queries, "offload_aggregate", program)
+    monkeypatch.setattr(jax, "block_until_ready", block_until_ready)
+    hx = HybridExecutor(edge_budget=1000)
+    ws = [np.zeros(4096, np.float32)] * 3
+    assert list(hx.run_windows(ws, "mean")) == [1.0, 2.0, 3.0]
+    assert asked == [True] * 3 and tpu.reads == 1
+    assert waits == [3] and hx.offload_waits == 1
+    assert hx.host_results == hx.offloads == 3
+
+
 def test_kmeans_and_linreg_services():
     import jax.numpy as jnp
     rng = np.random.default_rng(0)

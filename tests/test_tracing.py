@@ -105,3 +105,38 @@ def test_new_shape_lowers_once_and_warm_call_not_at_all(tmp_path,
     warm = _profile(tmp_path / "warm", lambda: hx.run_window(x, "sum"))
     assert [e for e in warm if e[0] == tracing.LOWERING_SPAN] == []
 
+
+@pytest.mark.parametrize("with_edge", [False, True])
+def test_run_windows_nests_one_launch_then_one_fetch(tmp_path, spans_on,
+                                                     with_edge):
+    hx = HybridExecutor(edge_budget=1000, interpret=True)
+    windows = [_window(4085, seed) for seed in range(3)]
+    if with_edge:
+        windows.insert(1, _window(500))
+    hx.run_window(windows[0], "mean")              # warm
+    got = []
+    events = _profile(tmp_path,
+                      lambda: got.extend(hx.run_windows(windows, "mean")))
+    assert got == pytest.approx([float(w.mean()) for w in windows],
+                                rel=1e-5)
+    names = [e[0] for e in events]
+    edge = ["repro.edge.run_window"] if with_edge else []
+    assert names == ["repro.offload.run_windows", "repro.offload.launch",
+                     *edge, "repro.offload.fetch"]
+    (_, ws, we, _), (_, ls, le, _) = events[:2]
+    _, fs, fe, _ = events[-1]
+    assert ws <= ls <= le <= fs <= fe <= we
+    if with_edge:
+        assert ls <= events[2][1] <= events[2][2] <= le
+
+
+def test_warm_run_windows_after_run_window_lowers_nothing(tmp_path,
+                                                          spans_on):
+    hx = HybridExecutor(edge_budget=0, interpret=True)
+    x = _window(4083)
+    hx.run_window(x, "sum")
+    warm = _profile(tmp_path,
+                    lambda: list(hx.run_windows([x, x[::-1].copy()],
+                                                "sum")))
+    assert [e for e in warm if e[0] == tracing.LOWERING_SPAN] == []
+    assert hx.offloads == 3 and hx.offload_waits == 2
